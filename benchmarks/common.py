@@ -15,8 +15,10 @@ FULL = os.environ.get("REPRO_FULL_BENCH", "0") == "1"
 SCALE = 1 if FULL else 8  # Table III dims divided by SCALE unless FULL
 
 
-def build_workload(layer: wl.Layer, strategy: str, mode: str, channel_latency: int):
-    """Returns (cfg, states, pending, job, layer)."""
+def build_workload(layer: wl.Layer, strategy: str, mode: str, channel_latency: int,
+                   use_kernel: bool = False):
+    """Returns (cfg, states, pending, job); ``use_kernel`` routes the
+    crossbar math through the Pallas kernel."""
     if strategy == "uniform":
         descs = sg.uniform(2, 2)
         mgrs, ids = [0, 1], {0: (0, 1), 1: (2, 3)}
@@ -47,7 +49,8 @@ def build_workload(layer: wl.Layer, strategy: str, mode: str, channel_latency: i
                   crossbars=job["crossbars"], scratch_init=job["scratch"])
     else:
         raise ValueError(mode)
-    cfg, states, pending = sg.build(descs, channel_latency=channel_latency, **kw)
+    cfg, states, pending = sg.build(descs, channel_latency=channel_latency,
+                                    use_kernel=use_kernel, **kw)
     return cfg, states, pending, job
 
 

@@ -118,6 +118,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.full:
         os.environ["REPRO_FULL_BENCH"] = "1"  # before benchmarks.common import
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     secs = sections()
     if args.list:

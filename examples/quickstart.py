@@ -15,7 +15,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))  # for benchmarks.*
 import numpy as np
 
 from benchmarks.common import build_workload, timed_run, verify
+from repro.compile_cache import enable_compile_cache
 from repro.vp import workloads as wl
+
+enable_compile_cache()
 
 layer = wl.TABLE_III[2].scaled(3)  # ImageNet-conv1, reduced for CPU (÷3 keeps compute ≫ sync overhead)
 print(f"workload: {layer.name}  O[{layer.h},{layer.p}] = A[{layer.h},{layer.w}] @ B[{layer.w},{layer.p}]\n")

@@ -34,6 +34,7 @@ import jax
 import numpy as np
 
 from repro import snn
+from repro.compile_cache import enable_compile_cache
 from repro.core.controller import Controller
 from repro.faults import FaultConfig, fidelity
 from repro.obs import TraceConfig
@@ -87,6 +88,7 @@ def main(argv=None):
                     help="comma-separated fault rates for the sweep")
     ap.add_argument("--seed", type=int, default=7, help="fault PRNG seed")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     job = snn.snn_inference_job(SIZES, t_steps=T_STEPS, rate=0.5, seed=2)
     descs = snn.segmentation_for(snn.n_units_for(job.layers), "uniform",

@@ -22,7 +22,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import numpy as np
 
 from repro import snn
+from repro.compile_cache import enable_compile_cache
 from repro.core.controller import Controller
+
+enable_compile_cache()
 
 GLYPHS = {  # 8x8 digit templates
     0: ["..####..", ".#....#.", "#......#", "#......#",

@@ -21,6 +21,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import numpy as np
 
 from repro import snn
+from repro.compile_cache import enable_compile_cache
 from repro.core.controller import Controller
 
 N_IN, N_EVID, N_CLASSES = 24, 16, 4
@@ -28,6 +29,7 @@ T_STEPS = 16
 SETTLE = 6  # extra ticks for the WTA competition to ring down
 N_TICKS = T_STEPS + 2 + SETTLE
 
+enable_compile_cache()
 rng = np.random.default_rng(5)
 
 # evidence layer: neuron j accumulates the input block of class j % 4

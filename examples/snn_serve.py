@@ -28,6 +28,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.serve.snn_serve import SnnServer, _normalize
 from repro.snn import workloads as wl
 
@@ -69,6 +70,7 @@ def main(argv=None):
                     help="fleet size")
     ap.add_argument("--seed", type=int, default=11, help="fleet PRNG seed")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # heterogeneous caps on purpose: half the fleet gets roomier channels,
     # yet _normalize folds caps out of the bucket key, so ONE bucket serves

@@ -29,6 +29,7 @@ import jax
 import numpy as np
 
 from repro import snn
+from repro.compile_cache import enable_compile_cache
 from repro.core.controller import Controller
 from repro.obs import TraceConfig, export
 
@@ -47,6 +48,7 @@ def main(argv=None):
                     help="also stream drained batches here as NDJSON "
                          "(one flat object per trace event)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     job = snn.hybrid_job(SIZES, t_steps=T_STEPS, rate=0.5, seed=2)
     cfg, states, pending, meta = snn.build_hybrid(job, "packed",

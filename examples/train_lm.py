@@ -12,7 +12,9 @@ Fault-tolerance demo (crash + auto-resume):
 """
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(sys.argv[1:] or ["--steps", "120", "--batch", "8", "--seq", "128"])

@@ -15,10 +15,12 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))  # for benchmarks.*
 import numpy as np
 
 from benchmarks.common import build_workload, timed_run, verify
+from repro.compile_cache import enable_compile_cache
 from repro.core import segmentation as sg
 from repro.core.controller import Controller
 from repro.vp import workloads as wl
 
+enable_compile_cache()
 layer = wl.TABLE_III[2].scaled(8)  # ImageNet-conv1
 print(f"workload: {layer.name} ({layer.h}x{layer.w}x{layer.p}), mode: cim offload\n")
 print(f"{'strategy':16s}{'segments':>9s}{'sq ms':>10s}{'pll ms':>10s}{'speedup':>9s}{'cycles':>12s}{'ok':>4s}")

@@ -269,13 +269,12 @@ class Controller:
                     exp = lambda t: jax.tree.map(lambda x: x[None], t)
                     return exp(st), exp(pen)
 
-                from repro.compat import shard_map
-
-                return shard_map(
+                return jax.shard_map(
                     body,
                     mesh=self.mesh,
                     in_specs=(P("segment"), P("segment")),
                     out_specs=(P("segment"), P("segment")),
+                    check_vma=False,
                 )(states, pending)
 
             self._shard_round = jax.jit(shard_round, donate_argnums=(0, 1))
@@ -778,14 +777,13 @@ def sharded_job_mega_fn(cfg, mesh, quantum: int = 10_000, obs=None,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     mega = _job_megaloop(cfg, quantum, obs)
     job = P(axis)
     rep = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         mega, mesh=mesh,
         in_specs=(job, job, job, job, job, job, job, job, rep, rep, rep),
         out_specs=(job, job, job, job, job),
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=(0, 1))

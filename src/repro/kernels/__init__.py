@@ -1,3 +1,10 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the VP's hot path (crossbar VMM, fused LIF step)."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run under the interpreter: only on the CPU
+    backend, where the tests run.  Everywhere else they compile, and a
+    kernel that fails to lower raises — it never falls back to the
+    interpreter or to the jnp reference.  Read at trace time."""
+    return jax.default_backend() == "cpu"

@@ -11,7 +11,9 @@ slices in registers/VMEM.  Block shapes are multiples of (8, 128) so both
 the MXU contraction (K = C) and the lane dimension stay hardware-aligned.
 
 Validated in interpret mode against ref.py (tests/test_kernels.py sweeps
-shapes, resolutions and dtypes with hypothesis).
+shapes, resolutions and dtypes with hypothesis), compiled for a described
+TPU v5e by tests/test_tpu_compile.py, and checked bit-equal to ref.py on the
+chip by chip_smoke.py.
 """
 from __future__ import annotations
 
@@ -62,7 +64,7 @@ def _vmm_body(x, w, o_ref, in_res: int, out_res: int):
 
 @functools.partial(jax.jit, static_argnames=("in_res", "out_res", "interpret"))
 def crossbar_vmm_tiles(x, weights, in_res: int = 8, out_res: int = 8,
-                       f_and=None, f_xor=None, interpret: bool = True):
+                       f_and=None, f_xor=None, *, interpret: bool):
     """x (B, C) int32, weights int8 (R, C) -> (B, R) int32.
 
     B and R are padded to tile multiples; C (the contraction) stays whole —

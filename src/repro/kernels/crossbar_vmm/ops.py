@@ -1,16 +1,16 @@
 """Jit'd public wrappers for the crossbar VMM kernel.
 
-``interpret=True`` on this CPU container (kernel body executed by the Pallas
-interpreter, semantics identical); on a real TPU deployment flip the flag.
+The kernel compiles for the TPU; on the CPU backend (tests) its body runs
+under the Pallas interpreter with identical semantics
+(``repro.kernels.interpret_mode``, read at trace time).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.crossbar_vmm.kernel import crossbar_vmm_tiles
-
-INTERPRET = True  # CPU container: no TPU lowering available
 
 
 def crossbar_vmm(weights, x, in_res: int = 8, out_res: int = 8,
@@ -18,7 +18,7 @@ def crossbar_vmm(weights, x, in_res: int = 8, out_res: int = 8,
     """weights int8 (R, C); x int32 (C,) -> int32 (R,); optional crossbar
     fault masks f_and/f_xor int8 (R, C) (repro.faults)."""
     return crossbar_vmm_tiles(x[None, :], weights, in_res, out_res,
-                              f_and, f_xor, interpret=INTERPRET)[0]
+                              f_and, f_xor, interpret=interpret_mode())[0]
 
 
 def crossbar_vmm_batch(weights, x, in_res: int = 8, out_res: int = 8,
@@ -38,4 +38,5 @@ def crossbar_vmm_batch(weights, x, in_res: int = 8, out_res: int = 8,
 
 def crossbar_matmul(weights, x, in_res: int = 8, out_res: int = 8):
     """weights (R, C) int8, x (C, N) int32 -> (R, N) — tiled GEMM form."""
-    return crossbar_vmm_tiles(x.T, weights, in_res, out_res, interpret=INTERPRET).T
+    return crossbar_vmm_tiles(x.T, weights, in_res, out_res,
+                              interpret=interpret_mode()).T

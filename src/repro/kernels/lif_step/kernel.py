@@ -9,11 +9,17 @@ VMEM.  This fusion is the point: the eager formulation materialises three
 (U, R) intermediates per tick; the kernel writes only the new state.
 
 Grid: (units, row_tiles).  Weights arrive pre-transposed (U, C, R) so the
-contraction is a plain (1, C) × (C, TILE_R) dot per block.  Per-unit LIF
-parameters (thresh/leak/refrac_period) ride along as length-1 blocks.
+contraction is a plain (1, C) × (C, TILE_R) dot per block.  Every per-unit
+operand carries a singleton middle axis — spikes (U, 1, C), neuron tiles
+(U, 1, R), the LIF parameters thresh/leak/refrac_period (U, 1, 1) — and the
+unit axis is squeezed out of each block, so every block's last two
+dimensions are either whole array dimensions or (1, 128)-multiples, as the
+TPU lowering requires.
 
 Validated in interpret mode against ref.py (tests/test_snn.py sweeps shapes
-and parameters; int32-exactness makes equality bit-strict).
+and parameters; int32-exactness makes equality bit-strict), compiled for a
+described TPU v5e by tests/test_tpu_compile.py, and checked bit-equal to
+ref.py on the chip by chip_smoke.py.
 """
 from __future__ import annotations
 
@@ -28,22 +34,32 @@ from repro.kernels.lif_step.ref import SPIKE_SAT
 TILE_R = 128  # neurons per program (lane-aligned)
 
 
+def _contract(s, w):
+    """(1, C) fp32 spike counts × (C, TILE_R) fp32 weights -> int32 charge.
+
+    Counts reach SPIKE_SAT = 511, which bf16 cannot hold exactly, so the dot
+    is pinned to full fp32 precision.  At the default precision a TPU v5e
+    rounds the operands to bf16, and the charge then differs from ref.py's
+    int32 contraction (chip_smoke.py's kernel phase checks bit-equality)."""
+    return jax.lax.dot(s, w, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
 def _kernel(s_ref, w_ref, x_ref, v_ref, r_ref, th_ref, lk_ref, rp_ref,
             vo_ref, ro_ref, so_ref):
-    """s (1, C) int32; w (1, C, TILE_R) int8; x (1, TILE_R) int32 extra
-    charge; v/r (1, TILE_R) int32; th/lk/rp (1,) int32
+    """s (1, C) int32; w (C, TILE_R) int8; x (1, TILE_R) int32 extra
+    charge; v/r (1, TILE_R) int32; th/lk/rp (1, 1) int32
     -> v'/r'/fired (1, TILE_R) int32."""
     # fan-in saturation (mirrors ref.py): bounds the accumulator inside
     # fp32's exact-integer range so the MXU contraction never rounds
     s = jnp.clip(s_ref[...], -SPIKE_SAT, SPIKE_SAT).astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)  # (C, TILE_R)
-    syn = jax.lax.dot(s, w, preferred_element_type=jnp.float32).astype(jnp.int32)
+    syn = _contract(s, w_ref[...].astype(jnp.float32))
     # extra charge from the layer's other column tiles (wide multi-crossbar
     # layers): already int32-exact, summed after the local contraction
     syn = syn + x_ref[...]
     v = v_ref[...]
     refrac = r_ref[...]
-    thresh, leak, rp = th_ref[0], lk_ref[0], rp_ref[0]
+    thresh, leak, rp = th_ref[...], lk_ref[...], rp_ref[...]
     active = refrac == 0
     v1 = jnp.maximum(v + jnp.where(active, syn, 0) - leak, 0)
     fired = active & (v1 >= thresh)
@@ -62,15 +78,15 @@ def _kernel_faults(s_ref, w_ref, a_ref, f_ref, x_ref, v_ref, r_ref, th_ref,
     masks (a = -1, f = 0, dd = dt = 0) reproduce ``_kernel`` bit-exactly,
     which is what lets one variant serve every fault-family combination.
 
-    a/f (1, C, TILE_R) int8; dd/dt (1, TILE_R) int32; rest as ``_kernel``.
+    a/f (C, TILE_R) int8; dd/dt (1, TILE_R) int32; rest as ``_kernel``.
     """
     s = jnp.clip(s_ref[...], -SPIKE_SAT, SPIKE_SAT).astype(jnp.float32)
-    w = ((w_ref[0] & a_ref[0]) ^ f_ref[0]).astype(jnp.float32)  # (C, TILE_R)
-    syn = jax.lax.dot(s, w, preferred_element_type=jnp.float32).astype(jnp.int32)
+    w = (w_ref[...] & a_ref[...]) ^ f_ref[...]  # (C, TILE_R)
+    syn = _contract(s, w.astype(jnp.float32))
     syn = syn + x_ref[...]
     v = v_ref[...]
     refrac = r_ref[...]
-    thresh, leak, rp = th_ref[0], lk_ref[0], rp_ref[0]
+    thresh, leak, rp = th_ref[...], lk_ref[...], rp_ref[...]
     dead = dd_ref[...] != 0
     active = (refrac == 0) & ~dead
     th_eff = jnp.maximum(thresh + dt_ref[...], 1)
@@ -84,7 +100,7 @@ def _kernel_faults(s_ref, w_ref, a_ref, f_ref, x_ref, v_ref, r_ref, th_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def lif_step_tiles(weights, spikes, v, refrac, thresh, leak, refrac_period,
                    extra=None, f_and=None, f_xor=None, dead=None, dth=None,
-                   interpret: bool = True):
+                   *, interpret: bool):
     """weights (U, R, C) int8; spikes (U, C) int32; v/refrac (U, R) int32;
     thresh/leak/refrac_period (U,) int32; extra (U, R) int32 or None
     -> (v', refrac', fired) each (U, R).
@@ -115,16 +131,20 @@ def lif_step_tiles(weights, spikes, v, refrac, thresh, leak, refrac_period,
     # (0 & 0) ^ 0 = 0 and dth pad 0 keeps th_eff = thresh >= 1.)
 
     grid = (u, rp_pad // TILE_R)
-    tile_spec = pl.BlockSpec((1, TILE_R), lambda i, j: (i, j))
-    unit_spec = pl.BlockSpec((1,), lambda i, j: (i,))
-    w_spec = pl.BlockSpec((1, c, TILE_R), lambda i, j: (i, 0, j))
+    # singleton middle axis on every per-unit operand; the squeezed unit
+    # axis (None) keeps each block's last two dims TPU-tileable
+    row = lambda x: x.reshape(u, 1, -1)
+    tile_spec = pl.BlockSpec((None, 1, TILE_R), lambda i, j: (i, 0, j))
+    unit_spec = pl.BlockSpec((None, 1, 1), lambda i, j: (i, 0, 0))
+    w_spec = pl.BlockSpec((None, c, TILE_R), lambda i, j: (i, 0, j))
     in_specs = [
-        pl.BlockSpec((1, c), lambda i, j: (i, 0)),
+        pl.BlockSpec((None, 1, c), lambda i, j: (i, 0, 0)),
         w_spec,
         tile_spec, tile_spec, tile_spec,
         unit_spec, unit_spec, unit_spec,
     ]
-    operands = [spikes, wt, xp, vp, rfp, thresh, leak, refrac_period]
+    operands = [row(spikes), wt, row(xp), row(vp), row(rfp), row(thresh),
+                row(leak), row(refrac_period)]
     kernel = _kernel
     if any(x is not None for x in (f_and, f_xor, dead, dth)):
         kernel = _kernel_faults
@@ -137,17 +157,13 @@ def lif_step_tiles(weights, spikes, v, refrac, thresh, leak, refrac_period,
                    else dth.astype(jnp.int32))
         in_specs = in_specs[:2] + [w_spec, w_spec] + in_specs[2:] + \
             [tile_spec, tile_spec]
-        operands = operands[:2] + [fa, fx] + operands[2:] + [dd, dt]
+        operands = operands[:2] + [fa, fx] + operands[2:] + [row(dd), row(dt)]
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[tile_spec, tile_spec, tile_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((u, rp_pad), jnp.int32),
-            jax.ShapeDtypeStruct((u, rp_pad), jnp.int32),
-            jax.ShapeDtypeStruct((u, rp_pad), jnp.int32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((u, 1, rp_pad), jnp.int32)] * 3,
         interpret=interpret,
     )(*operands)
-    return out[0][:, :r], out[1][:, :r], out[2][:, :r]
+    return tuple(o[:, 0, :r] for o in out)

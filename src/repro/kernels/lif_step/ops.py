@@ -1,15 +1,15 @@
 """Jit'd public wrappers for the fused LIF step kernel.
 
-``interpret=True`` on this CPU container (kernel body executed by the Pallas
-interpreter, semantics identical); on a real TPU deployment flip the flag.
+The kernel compiles for the TPU; on the CPU backend (tests) its body runs
+under the Pallas interpreter with identical semantics
+(``repro.kernels.interpret_mode``, read at trace time).
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.lif_step.kernel import lif_step_tiles
-
-INTERPRET = True  # CPU container: no TPU lowering available
 
 
 def lif_step_units(weights, spikes, v, refrac, thresh, leak, refrac_period,
@@ -28,7 +28,7 @@ def lif_step_units(weights, spikes, v, refrac, thresh, leak, refrac_period,
     """
     return lif_step_tiles(weights, spikes, v, refrac, thresh, leak,
                           refrac_period, extra, f_and, f_xor, dead, dth,
-                          interpret=INTERPRET)
+                          interpret=interpret_mode())
 
 
 def lif_step(weights, spikes, v, refrac, thresh, leak, refrac_period):

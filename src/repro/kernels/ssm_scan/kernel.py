@@ -47,7 +47,7 @@ def _kernel(da_ref, dbx_ref, c_ref, y_ref, h_ref, *, chunk: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssm_scan(da, dbx, c, interpret: bool = True):
+def ssm_scan(da, dbx, c, *, interpret: bool):
     """da, dbx (B, S, D, N) fp32; c (B, S, N) fp32 -> y (B, S, D) fp32.
 
     h0 = 0 (prefill/train); decode uses the O(1) jnp path instead.

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.common import named, shape_dtypes, shardings
 from repro.configs import ARCH_IDS, SHAPES, all_cells, get_config, skipped_cells
 from repro.launch.mesh import chips, make_production_mesh
@@ -111,7 +110,7 @@ def lower_cell(arch: str, shape_name: str, mesh, donate: bool = True, opt: bool 
 def run_cell(arch: str, shape_name: str, mesh_kind: str, verbose: bool = True, opt: bool = False):
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = lower_cell(arch, shape_name, mesh, opt=opt)
         t_lower = time.time() - t0
         compiled = lowered.compile()

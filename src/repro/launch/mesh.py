@@ -6,7 +6,15 @@ importing this module never touches jax device state.
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axis types: sharding is propagated by the
+    compiler (jit shardings, ``shard_map`` specs), not carried in types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -34,7 +42,5 @@ def make_serve_mesh(n_devices: int | None = None):
     actually has (or ``n_devices``, e.g. under
     ``--xla_force_host_platform_device_count``).
     """
-    import jax
-
     n = n_devices or len(jax.devices())
     return make_mesh((n,), ("jobs",))

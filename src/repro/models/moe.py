@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.common import DTypePolicy, ParamSpec
-from repro.compat import shard_map
 from repro.models.layers import DATA_AXES, mlp_specs, apply_mlp
 
 
@@ -165,11 +164,12 @@ def ep_moe(cfg, p, x, policy: DTypePolicy, mesh, fsdp: bool = False):
     avail = set(mesh.axis_names)
     baxes = tuple(a for a in DATA_AXES if a in avail)
     all_axes = tuple(a for a in ("pod", "data", "model") if a in avail)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(pspecs, P(baxes, None, None)),
         out_specs=(P(baxes, None, None), P()),
+        check_vma=False,
     )
     return fn(p, x)
 
@@ -250,10 +250,11 @@ def ep_moe_decode(cfg, p, x, policy: DTypePolicy, mesh, fsdp: bool):
             "w_gate": P(None, "model"),
             "w_out": P("model", None),
         }
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pspecs, P(baxes, None, None)),
         out_specs=(P(baxes, None, None), P()),
+        check_vma=False,
     )
     return fn(p, x)
 

@@ -199,12 +199,13 @@ def test_conformance_shard_map(subproc):
     subproc(
         """
 import jax, numpy as np
-from repro import compat, snn
+from repro import snn
+from repro.launch.mesh import make_mesh
 from repro.core import segmentation as sg
 from repro.core.controller import Controller
 from repro.vp import workloads as wl
 
-mesh = compat.make_mesh((2,), ("segment",))
+mesh = make_mesh((2,), ("segment",))
 
 def both(cfg, states, pending, quantum):
     res = {}
@@ -370,12 +371,13 @@ def test_conformance_shard_map_traced(subproc):
     subproc(
         """
 import jax, numpy as np
-from repro import compat, snn
+from repro import snn
+from repro.launch.mesh import make_mesh
 from repro.core.controller import Controller
 from repro.obs import TraceConfig
 from repro.obs import trace as tr
 
-mesh = compat.make_mesh((2,), ("segment",))
+mesh = make_mesh((2,), ("segment",))
 ff = snn.snn_inference_job((24, 16, 8), t_steps=6, rate=0.5, seed=2)
 descs = snn.segmentation_for(ff.layers, "uniform", n_segments=2)
 cfg, states, pending, _ = snn.build_snn(ff.layers, descs, ff.raster)

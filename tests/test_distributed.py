@@ -13,7 +13,7 @@ def test_moe_ep_matches_dense(subproc):
     subproc(
         """
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_smoke_config
 from repro.models.moe import apply_moe, moe_specs
 from repro.common import init_params
@@ -21,12 +21,12 @@ import dataclasses
 
 cfg = get_smoke_config("kimi-k2-1t-a32b")
 cfg = dataclasses.replace(cfg, d_model=64)
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 specs = moe_specs(cfg, tp=2)
 params = init_params(jax.random.PRNGKey(0), specs)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64), jnp.float32)
 y_dense, aux_d = apply_moe(cfg, params, x, __import__("repro.common", fromlist=["DTypePolicy"]).DTypePolicy(), mesh=None)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_ep, aux_e = jax.jit(lambda p, x: apply_moe(cfg, p, x,
         __import__("repro.common", fromlist=["DTypePolicy"]).DTypePolicy(), mesh=mesh))(params, x)
 # EP uses capacity-dropless path at this size: must match dense exactly-ish
@@ -42,7 +42,7 @@ def test_shard_map_backend_matches_vmap(subproc):
     subproc(
         """
 import jax, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import segmentation as sg
 from repro.core.controller import Controller
 from repro.vp import workloads as wl
@@ -53,7 +53,7 @@ job = wl.cim_workload(layer, mgr_segments=[0, 1], cim_ids_per_mgr={0: (0, 1), 1:
 cfg, states, pending = sg.build(descs, programs=job["programs"], dram_words=job["dram"],
                                 crossbars=job["crossbars"], scratch_init=job["scratch"],
                                 channel_latency=2000)
-mesh = compat.make_mesh((2,), ("segment",))
+mesh = make_mesh((2,), ("segment",))
 res = {}
 for backend, kw in (("vmap", {}), ("shard_map", {"mesh": mesh})):
     ctl = Controller(cfg, states, pending, backend=backend, quantum=1000, **kw)
@@ -74,13 +74,14 @@ def test_shard_map_backend_matches_vmap_snn(subproc):
     subproc(
         """
 import jax, numpy as np
-from repro import compat, snn
+from repro import snn
+from repro.launch.mesh import make_mesh
 from repro.core.controller import Controller
 
 job = snn.snn_inference_job((16, 12, 8), t_steps=6, rate=0.6, seed=5)
 descs = snn.segmentation_for(2, "uniform", n_segments=2)
 cfg, states, pending, meta = snn.build_snn(job.layers, descs, job.raster)
-mesh = compat.make_mesh((2,), ("segment",))
+mesh = make_mesh((2,), ("segment",))
 res = {}
 for backend, kw in (("vmap", {}), ("shard_map", {"mesh": mesh})):
     ctl = Controller(cfg, states, pending, backend=backend, quantum=32, **kw)
@@ -104,15 +105,15 @@ def test_elastic_checkpoint_restore(subproc, tmp_path):
     subproc(
         f"""
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.train import checkpoint as ckpt
 
-mesh4 = compat.make_mesh((4, 2), ("data", "model"))
+mesh4 = make_mesh((4, 2), ("data", "model"))
 x = jnp.arange(64 * 8, dtype=jnp.float32).reshape(64, 8)
 xs = jax.device_put(x, NamedSharding(mesh4, P("data", "model")))
 ckpt.save(r"{tmp_path}", 5, {{"w": xs}})
-mesh2 = compat.make_mesh((2, 4), ("data", "model"))
+mesh2 = make_mesh((2, 4), ("data", "model"))
 restored, at = ckpt.restore(r"{tmp_path}", {{"w": x}},
     shardings={{"w": NamedSharding(mesh2, P("data", "model"))}})
 assert at == 5
@@ -128,11 +129,11 @@ def test_hlo_cost_counts_sharded_collectives(subproc):
     subproc(
         """
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.analysis.hlo_cost import analyze
 
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 def f(w, x):
     def body(c, _):
         return jnp.tanh(c @ w), None
@@ -140,7 +141,7 @@ def f(w, x):
     return y.sum()
 w = jax.ShapeDtypeStruct((256, 256), jnp.float32)
 x = jax.ShapeDtypeStruct((256, 256), jnp.float32)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     c = jax.jit(f, in_shardings=(NamedSharding(mesh, P(None, "model")),
                                  NamedSharding(mesh, P("data", None))),
                 out_shardings=NamedSharding(mesh, P())).lower(w, x).compile()

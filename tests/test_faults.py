@@ -181,10 +181,11 @@ def test_faults_shard_map_conformance(subproc):
     subproc(
         """
 import jax, numpy as np
-from repro import compat, faults as flt, snn
+from repro import faults as flt, snn
+from repro.launch.mesh import make_mesh
 from repro.core.controller import Controller
 
-mesh = compat.make_mesh((2,), ("segment",))
+mesh = make_mesh((2,), ("segment",))
 job = snn.snn_inference_job((32, 24, 10), t_steps=8, rate=0.5, seed=2)
 descs = snn.segmentation_for(snn.n_units_for(job.layers), "uniform",
                              n_segments=2)

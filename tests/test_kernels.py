@@ -72,3 +72,30 @@ def test_ssm_scan_kernel_matches_ref(b, s, d, n, seed):
     y_ref, _ = ssm_ref.selective_scan(da, dbx, c, h0)
     y_ker = ssm_ops.ssm_scan(da, dbx, c)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_ker), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("backend, interpret", [("cpu", True), ("tpu", False)])
+def test_kernel_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    """The ops wrappers interpret the kernel bodies on the CPU backend only;
+    on a TPU they compile them (the backend is read at trace time)."""
+    import jax
+
+    from repro.kernels.lif_step import ops as lif_ops
+
+    seen = {}
+
+    def record(name):
+        def kernel(*args, interpret, **kw):
+            seen[name] = interpret
+            return np.zeros((1, 1))
+        return kernel
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(xb_ops, "crossbar_vmm_tiles", record("crossbar_vmm"))
+    monkeypatch.setattr(lif_ops, "lif_step_tiles", record("lif_step"))
+    monkeypatch.setattr(ssm_ops, "_ssm_scan", record("ssm_scan"))
+    xb_ops.crossbar_vmm(None, np.zeros(1))
+    lif_ops.lif_step_units(*[None] * 7)
+    ssm_ops.ssm_scan(None, None, None)
+    assert seen == {"crossbar_vmm": interpret, "lif_step": interpret,
+                    "ssm_scan": interpret}

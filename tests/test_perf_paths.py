@@ -52,7 +52,7 @@ def test_moe_decode_path_matches_dense(subproc):
     subproc(
         """
 import jax, jax.numpy as jnp, numpy as np, dataclasses
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.configs import get_smoke_config
 from repro.models.moe import apply_moe
 from repro.models.transformer import decoder_specs
@@ -61,13 +61,13 @@ from repro.common import init_params, DTypePolicy
 
 cfg = get_smoke_config("kimi-k2-1t-a32b")
 cfg = dataclasses.replace(cfg, d_model=64)
-mesh = compat.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 specs = moe_specs(cfg, tp=2)
 params = init_params(jax.random.PRNGKey(0), specs)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 1, 64), jnp.float32)  # decode shape
 pol = DTypePolicy()
 y_ref, _ = apply_moe(cfg, params, x, pol, mesh=None)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_dec, _ = jax.jit(lambda p, x: apply_moe(cfg, p, x, pol, mesh=mesh, decode=True))(params, x)
 np.testing.assert_allclose(np.asarray(y_dec, np.float32), np.asarray(y_ref, np.float32),
                            rtol=2e-2, atol=2e-2)

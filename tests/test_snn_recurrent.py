@@ -242,14 +242,15 @@ def test_recurrent_shard_map_matches_vmap(subproc):
     subproc(
         """
 import jax, numpy as np
-from repro import compat, snn
+from repro import snn
+from repro.launch.mesh import make_mesh
 from repro.core.controller import Controller
 
 job = snn.snn_recurrent_job((24, 20, 8), t_steps=8, rate=0.5, seed=3)
 descs = snn.segmentation_for(job.layers, "uniform", n_segments=2, edges=job.edges)
 cfg, states, pending, meta = snn.build_snn(job.layers, descs, job.raster,
                                            edges=job.edges, n_ticks=job.n_ticks)
-mesh = compat.make_mesh((2,), ("segment",))
+mesh = make_mesh((2,), ("segment",))
 res = {}
 for backend, kw in (("vmap", {}), ("shard_map", {"mesh": mesh})):
     ctl = Controller(cfg, states, pending, backend=backend, quantum=32, **kw)
