@@ -1,0 +1,9 @@
+"""Share of the device's busy time in the traced window spent under the
+phase ``vp.store_replay`` (the DRAM store-log replay), in %: the self time
+of the megaloop's operations under that scope (``bench/phases.py``) over
+the union of all device operations, averaged over the chips."""
+from bench import phases
+
+
+def read(ctx):
+    return phases.busy_pct(ctx, "vp.store_replay")

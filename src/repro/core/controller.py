@@ -37,8 +37,10 @@ from __future__ import annotations
 
 import concurrent.futures as cf
 import dataclasses
+import hashlib
+import itertools
 import time as _time
-from functools import partial
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -46,11 +48,14 @@ import numpy as np
 
 from repro.core import channel as ch
 from repro.obs import trace as obs_trace
+from repro.obs.profile import phase, span
 from repro.vp import platform as pf
 
 
 _FN_CACHE: dict = {}  # (cfg, quantum, s, obs) -> compiled fns; benchmarks
                       # rebuild controllers per workload with identical shapes
+
+_SERIALS = itertools.count()  # Controller.serial, for the host spans
 
 # the single host-transfer primitive for dispatch-boundary syncs: every
 # fused-dispatch fetch (round count + flags + telemetry ring) goes through
@@ -71,10 +76,16 @@ class Controller:
     obs: object = None  # obs.trace.TraceConfig, or None = tracing compiled out
 
     def __post_init__(self):
+        self.serial = next(_SERIALS)  # names this controller's host spans
+        with span("vp.controller.init", controller=self.serial):
+            self._init()
+
+    def _init(self):
         # own the state: round fns donate their inputs, so the caller's
         # arrays must not be shared with this controller
-        self.states = jax.tree.map(jnp.copy, self.states)
-        self.pending = jax.tree.map(jnp.copy, self.pending)
+        with span("vp.controller.copy", controller=self.serial):
+            self.states = jax.tree.map(jnp.copy, self.states)
+            self.pending = jax.tree.map(jnp.copy, self.pending)
         # telemetry (obs/): attach one trace ring per segment INSIDE the
         # state pytree, so the megaloop carries it and the step appends to
         # it in traced code; host-side bookkeeping for drained batches.
@@ -98,9 +109,10 @@ class Controller:
         # into the initial pending box — detect that once and fall back to
         # the full step (one host check at construction, never per round)
         if not self.cfg.has_cpu:
-            injected = np.asarray(
-                self.pending["valid"] & (self.pending["kind"] != ch.MSG_SPIKE)
-            )
+            with span("vp.controller.mmio_check", controller=self.serial):
+                injected = np.asarray(
+                    self.pending["valid"] & (self.pending["kind"] != ch.MSG_SPIKE)
+                )
             if injected.any():
                 self.cfg = dataclasses.replace(self.cfg, has_cpu=True)
         self.lat = self.cfg.latency_matrix()
@@ -133,6 +145,7 @@ class Controller:
         lat = self.lat
         quantum = self.quantum
 
+        @phase("vp.limits")
         def limits(times):
             # limit_i = min_{j != i}(t_j + lat[j, i]); single segment: t + q
             tl = times[:, None] + lat  # (src, dst)
@@ -146,8 +159,10 @@ class Controller:
         def vmap_round(states, pending):
             lim = limits(states["time"])
             states, outboxes, pending = jax.vmap(step)(states, pending, lim)
-            fresh = ch.route(outboxes, lat, cfg.in_cap)
-            pending = jax.vmap(ch.merge_pending)(pending, fresh)
+            with phase("vp.route"):
+                fresh = ch.route(outboxes, lat, cfg.in_cap)
+            with phase("vp.merge"):
+                pending = jax.vmap(ch.merge_pending)(pending, fresh)
             return states, pending
 
         def megaloop(round_fn):
@@ -162,6 +177,7 @@ class Controller:
             per-round path would, so the message is identical too)."""
 
             def mega(states, pending, r0, k, check_every):
+                @phase("vp.check")
                 def cond(carry):
                     _, _, i, done, over = carry
                     return ~(done | over) & (i < k)
@@ -169,34 +185,35 @@ class Controller:
                 def body(carry):
                     st, pen, i, _, _ = carry
                     st, pen = round_fn(st, pen)
-                    i = i + 1
-                    at_check = ((r0 + i) % check_every) == 0
+                    with phase("vp.check"):
+                        i = i + 1
+                        at_check = ((r0 + i) % check_every) == 0
 
-                    def checked(_):
-                        done, in_over, out_over, st_over, late, _tr = \
-                            pf.termination_flags(
-                                st, pen, cfg.in_cap, cfg.out_cap, cfg.store_log)
-                        # the trace-overflow flag (6) is informational and
-                        # never stops the loop: telemetry loss must not
-                        # change termination behavior (obs/trace.py).  Under
-                        # the graceful-degradation overflow policy
-                        # (faults.FaultConfig(on_overflow="drop")) the
-                        # channel watermarks stop being fatal too — overflow
-                        # is counted spike loss and the run continues; the
-                        # program-bug flags (store log, late MMIO) still
-                        # abort.  Static branch: the policy is part of the
-                        # cached-function key, like every fault gate.
-                        if cfg.faults is not None and cfg.faults.drop_overflow:
-                            over = st_over | late
-                        else:
-                            over = in_over | out_over | st_over | late
-                        return done & ~over, over
+                        def checked(_):
+                            done, in_over, out_over, st_over, late, _tr = \
+                                pf.termination_flags(
+                                    st, pen, cfg.in_cap, cfg.out_cap, cfg.store_log)
+                            # the trace-overflow flag (6) is informational and
+                            # never stops the loop: telemetry loss must not
+                            # change termination behavior (obs/trace.py).  Under
+                            # the graceful-degradation overflow policy
+                            # (faults.FaultConfig(on_overflow="drop")) the
+                            # channel watermarks stop being fatal too — overflow
+                            # is counted spike loss and the run continues; the
+                            # program-bug flags (store log, late MMIO) still
+                            # abort.  Static branch: the policy is part of the
+                            # cached-function key, like every fault gate.
+                            if cfg.faults is not None and cfg.faults.drop_overflow:
+                                over = st_over | late
+                            else:
+                                over = in_over | out_over | st_over | late
+                            return done & ~over, over
 
-                    # cond, not where: non-check rounds skip the reductions
-                    done, over = jax.lax.cond(
-                        at_check, checked,
-                        lambda _: (jnp.array(False), jnp.array(False)), None)
-                    return st, pen, i, done, over
+                        # cond, not where: non-check rounds skip the reductions
+                        done, over = jax.lax.cond(
+                            at_check, checked,
+                            lambda _: (jnp.array(False), jnp.array(False)), None)
+                        return st, pen, i, done, over
 
                 zero, false = jnp.int32(0), jnp.array(False)
                 return jax.lax.while_loop(
@@ -205,28 +222,30 @@ class Controller:
 
             return mega
 
-        key = (self.cfg, self.quantum, s, self.obs)
-        if key not in _FN_CACHE:
-            _FN_CACHE[key] = {
-                "vmap_round": jax.jit(vmap_round, donate_argnums=(0, 1)),
-                "vmap_mega": jax.jit(megaloop(vmap_round), donate_argnums=(0, 1)),
-                "flags": jax.jit(
-                    lambda states, pending: jnp.stack(pf.termination_flags(
-                        states, pending, cfg.in_cap, cfg.out_cap, cfg.store_log))
-                ),
-                "step_one": jax.jit(step),
-                "limits": jax.jit(limits),
-                "route": jax.jit(lambda outboxes: ch.route(outboxes, lat, cfg.in_cap)),
-                "merge_one": jax.jit(ch.merge_pending, donate_argnums=(0,)),
-            }
-        fns = _FN_CACHE[key]
-        self._vmap_round = fns["vmap_round"]
-        self._vmap_mega = fns["vmap_mega"]
-        self._flags_fn = fns["flags"]
-        self._step_one = fns["step_one"]
-        self._limits = fns["limits"]
-        self._route = fns["route"]
-        self._merge_one = fns["merge_one"]
+        with span("vp.controller.fn_cache", controller=self.serial):
+            key = (self.cfg, self.quantum, s, self.obs)
+            if key not in _FN_CACHE:
+                _FN_CACHE[key] = {
+                    "vmap_round": jax.jit(vmap_round, donate_argnums=(0, 1)),
+                    "vmap_mega": jax.jit(megaloop(vmap_round), donate_argnums=(0, 1)),
+                    "flags": jax.jit(
+                        lambda states, pending: jnp.stack(pf.termination_flags(
+                            states, pending, cfg.in_cap, cfg.out_cap, cfg.store_log))
+                    ),
+                    "step_one": jax.jit(step),
+                    "limits": jax.jit(limits),
+                    "route": jax.jit(lambda outboxes: ch.route(outboxes, lat, cfg.in_cap)),
+                    "merge_one": jax.jit(ch.merge_pending, donate_argnums=(0,)),
+                }
+            fns = _FN_CACHE[key]
+            self._vmap_round = fns["vmap_round"]
+            self._vmap_mega = fns["vmap_mega"]
+            self._flags_fn = fns["flags"]
+            self._step_one = fns["step_one"]
+            self._limits = fns["limits"]
+            self._route = fns["route"]
+            self._merge_one = fns["merge_one"]
+            self._fns = fns
 
         if self.backend == "shard_map":
             from jax.sharding import PartitionSpec as P
@@ -238,34 +257,37 @@ class Controller:
                     # leading segment axis is mapped: local shapes (1, ...)
                     my = jax.tree.map(lambda x: x[0], states1)
                     pen = jax.tree.map(lambda x: x[0], pending1)
-                    seg_times = jax.lax.all_gather(my["time"], "segment")
-                    i = jax.lax.axis_index("segment")
-                    tl = seg_times + self.lat[:, i]
-                    tl = jnp.where(jnp.arange(s) == i, big, tl)
-                    lim = tl.min()
+                    with phase("vp.limits"):
+                        seg_times = jax.lax.all_gather(my["time"], "segment")
+                        i = jax.lax.axis_index("segment")
+                        tl = seg_times + self.lat[:, i]
+                        tl = jnp.where(jnp.arange(s) == i, big, tl)
+                        lim = tl.min()
                     st, outbox, pen = step(my, pen, lim)
-                    all_out = jax.lax.all_gather(outbox, "segment")  # (S, cap)
-                    t_avail = all_out["t_emit"] + self.lat[
-                        jnp.repeat(jnp.arange(s), self.cfg.out_cap).reshape(s, self.cfg.out_cap), i
-                    ]
-                    flat_valid = (all_out["valid"] & (all_out["dst"] == i)).reshape(-1)
-                    rank = jnp.cumsum(flat_valid.astype(jnp.int32)) - 1
-                    # dead lanes scatter out-of-bounds and drop (channel.py's
-                    # "never write a dead slot" rule); past-cap lanes drop
-                    # too — same drop-the-tail semantics as route(), so the
-                    # graceful-degradation overflow policy loses the
-                    # identical messages on this backend as on the others
-                    # (count below still records true demand for the
-                    # watermark and the lost_total accounting)
-                    pos = jnp.where(flat_valid & (rank < self.cfg.in_cap),
-                                    rank, self.cfg.in_cap)
-                    fresh = ch.empty_pending(self.cfg.in_cap)
-                    for f, src in (("kind", all_out["kind"]), ("addr", all_out["addr"]),
-                                   ("data", all_out["data"]), ("t_avail", t_avail)):
-                        fresh[f] = fresh[f].at[pos].set(src.reshape(-1), mode="drop")
-                    fresh["valid"] = fresh["valid"].at[pos].set(flat_valid, mode="drop")
-                    fresh["count"] = flat_valid.sum().astype(jnp.int32)
-                    pen = ch.merge_pending(pen, fresh)
+                    with phase("vp.route"):
+                        all_out = jax.lax.all_gather(outbox, "segment")  # (S, cap)
+                        t_avail = all_out["t_emit"] + self.lat[
+                            jnp.repeat(jnp.arange(s), self.cfg.out_cap).reshape(s, self.cfg.out_cap), i
+                        ]
+                        flat_valid = (all_out["valid"] & (all_out["dst"] == i)).reshape(-1)
+                        rank = jnp.cumsum(flat_valid.astype(jnp.int32)) - 1
+                        # dead lanes scatter out-of-bounds and drop (channel.py's
+                        # "never write a dead slot" rule); past-cap lanes drop
+                        # too — same drop-the-tail semantics as route(), so the
+                        # graceful-degradation overflow policy loses the
+                        # identical messages on this backend as on the others
+                        # (count below still records true demand for the
+                        # watermark and the lost_total accounting)
+                        pos = jnp.where(flat_valid & (rank < self.cfg.in_cap),
+                                        rank, self.cfg.in_cap)
+                        fresh = ch.empty_pending(self.cfg.in_cap)
+                        for f, src in (("kind", all_out["kind"]), ("addr", all_out["addr"]),
+                                       ("data", all_out["data"]), ("t_avail", t_avail)):
+                            fresh[f] = fresh[f].at[pos].set(src.reshape(-1), mode="drop")
+                        fresh["valid"] = fresh["valid"].at[pos].set(flat_valid, mode="drop")
+                        fresh["count"] = flat_valid.sum().astype(jnp.int32)
+                    with phase("vp.merge"):
+                        pen = ch.merge_pending(pen, fresh)
                     exp = lambda t: jax.tree.map(lambda x: x[None], t)
                     return exp(st), exp(pen)
 
@@ -483,6 +505,12 @@ class Controller:
         dispatch sync (the flags tuple and the ring travel in ONE
         ``jax.device_get``), so telemetry adds zero extra device syncs.
         """
+        with span("vp.run", controller=self.serial):
+            return self._run(max_rounds, check_every, fused,
+                             rounds_per_dispatch, on_telemetry)
+
+    def _run(self, max_rounds, check_every, fused, rounds_per_dispatch,
+             on_telemetry):
         t0 = _time.perf_counter()
         self._require_open()
         if self._finished:
@@ -507,21 +535,25 @@ class Controller:
             ran = 0
             while ran < max_rounds:
                 k = min(rounds_per_dispatch, max_rounds - ran)
-                self.states, self.pending, i, d, o = mega(
-                    self.states, self.pending,
-                    jnp.int32(ran), jnp.int32(k), jnp.int32(check_every),
-                )
+                args = (self.states, self.pending,
+                        jnp.int32(ran), jnp.int32(k), jnp.int32(check_every))
+                if self.backend == "vmap":
+                    _remember(self._fns, args)
+                n = self.dispatches
+                with span("vp.dispatch", controller=self.serial, dispatch=n):
+                    self.states, self.pending, i, d, o = mega(*args)
                 self.dispatches += 1
                 # the one host sync per dispatch: scalars AND the telemetry
                 # ring come back in a single transfer
-                if self.obs is None:
-                    i, d, o = self._fetch((i, d, o))
-                else:
-                    i, d, o, ring = self._fetch(
-                        (i, d, o, self.states["trace"]))
-                    self._ingest(ring, on_telemetry)
-                    self.states = {**self.states,
-                                   "trace": obs_trace.reset(self.states["trace"])}
+                with span("vp.sync", controller=self.serial, dispatch=n):
+                    if self.obs is None:
+                        i, d, o = self._fetch((i, d, o))
+                    else:
+                        i, d, o, ring = self._fetch(
+                            (i, d, o, self.states["trace"]))
+                        self._ingest(ring, on_telemetry)
+                        self.states = {**self.states,
+                                       "trace": obs_trace.reset(self.states["trace"])}
                 i = int(i)
                 ran += i
                 self.rounds_run += i
@@ -531,7 +563,9 @@ class Controller:
             if over or not done:
                 # a watermark tripped at a check point, or the loop exhausted
                 # max_rounds without the predicate ever seeing the last rounds
-                self._check_overflow()
+                with span("vp.overflow_check", controller=self.serial,
+                          dispatch=self.dispatches):
+                    self._check_overflow()
             self._finished = done and not over
         else:
             for r in range(max_rounds):
@@ -553,7 +587,9 @@ class Controller:
                 self._check_overflow()  # done() may never have seen the last rounds
             if self.obs is not None:
                 self.drain_telemetry(on_telemetry)
-        self.block_until_ready()
+        with span("vp.block", controller=self.serial,
+                  dispatch=self.dispatches):
+            self.block_until_ready()
         return self.rounds_run, _time.perf_counter() - t0
 
     # ------------------------------------------------------------------
@@ -580,6 +616,53 @@ class Controller:
         from repro.obs import metrics as obs_metrics
 
         return obs_metrics.collect(self._stacked(), self._pending_stacked())
+
+
+def _spec(x):
+    """The shape of a dispatched argument, as ``jit`` saw it: a committed
+    array's sharding is part of the program, an uncommitted one's is not."""
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+
+
+def _remember(fns, args):
+    """Keep the argument shapes of a ``vmap`` megaloop dispatch, one entry
+    for each program ``jit`` compiles from them."""
+    specs, tree = jax.tree.flatten(jax.tree.map(_spec, args))
+    fns.setdefault("mega_args", {}).setdefault(
+        (tree, tuple(specs)), jax.tree.unflatten(tree, specs))
+
+
+def dispatched_megaloop_hlo() -> list:
+    """The optimized HLO text of every ``vmap`` megaloop program this
+    process has dispatched, compiled again from its argument shapes.  A
+    profiler trace names each device operation by its instruction; the
+    ``op_name`` metadata of this text maps each instruction to the round's
+    phase (``bench/phases.py``)."""
+    return [_own_text(fns["vmap_mega"], args)
+            for fns in _FN_CACHE.values()
+            for args in fns.get("mega_args", {}).values()]
+
+
+def _own_text(mega, args) -> str:
+    """The compiled text of ``mega`` on ``args`` with this code's metadata.
+
+    The persistent compile cache keys a program without its debug
+    information, so the executable a dispatch ran may carry the ``op_name``
+    metadata of whichever checkout compiled the program first.  Compiled
+    under a module name that hashes this process's own lowering, metadata
+    included, it is the same program (only the module name differs) with
+    the metadata of the code that runs here."""
+    raw = mega.__wrapped__
+    own_ir = mega.lower(*args).as_text(debug_info=True)
+
+    @wraps(raw)
+    def own(*a):
+        return raw(*a)
+
+    own.__name__ = own.__qualname__ = (
+        "mega_" + hashlib.sha256(own_ir.encode()).hexdigest()[:16])
+    return jax.jit(own, donate_argnums=(0, 1)).lower(*args).compile().as_text()
 
 
 def overflow_error(states, pending, *, in_cap: int, out_cap: int,
@@ -670,6 +753,7 @@ def _job_megaloop(cfg, quantum, obs):
     lat = cfg.latency_matrix()
     big = jnp.int32(2**30)
 
+    @phase("vp.limits")
     def limits(times):
         tl = times[:, None] + lat
         tl = jnp.where(jnp.eye(s, dtype=bool), big, tl)
@@ -681,8 +765,10 @@ def _job_megaloop(cfg, quantum, obs):
     def vmap_round(states, pending):
         lim = limits(states["time"])
         states, outboxes, pending = jax.vmap(step)(states, pending, lim)
-        fresh = ch.route(outboxes, lat, cfg.in_cap)
-        pending = jax.vmap(ch.merge_pending)(pending, fresh)
+        with phase("vp.route"):
+            fresh = ch.route(outboxes, lat, cfg.in_cap)
+        with phase("vp.merge"):
+            pending = jax.vmap(ch.merge_pending)(pending, fresh)
         return states, pending
 
     job_round = jax.vmap(vmap_round)
@@ -704,14 +790,17 @@ def _job_megaloop(cfg, quantum, obs):
         per-job.
         """
 
+        @phase("vp.check")
         def cond(carry):
             _st, _pen, i, _r, done, over = carry
             return jnp.any(~(done | over)) & (i < k)
 
         def body(carry):
             st, pen, i, rounds, done, over = carry
-            active = ~(done | over)
+            with phase("vp.freeze"):
+                active = ~(done | over)
 
+            @phase("vp.freeze")
             def freeze(new, old):
                 keep = lambda n, o: jnp.where(
                     active.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
@@ -719,28 +808,30 @@ def _job_megaloop(cfg, quantum, obs):
 
             st_n, pen_n = job_round(st, pen)
             st, pen = freeze(st_n, st), freeze(pen_n, pen)
-            rounds = rounds + active.astype(jnp.int32)
-            i = i + 1
-            at_check = ((r0 + i) % check_every) == 0
+            with phase("vp.freeze"):
+                rounds = rounds + active.astype(jnp.int32)
+            with phase("vp.check"):
+                i = i + 1
+                at_check = ((r0 + i) % check_every) == 0
 
-            def checked(_):
-                d, in_o, out_o, st_o, late, _tr = pf.job_termination_flags(
-                    st, pen, in_cap, out_cap, store_log)
-                # same policy split as the solo megaloop: under the
-                # graceful-degradation overflow policy the channel
-                # watermarks are counted loss, not aborts
-                if cfg.faults is not None and cfg.faults.drop_overflow:
-                    o = st_o | late
-                else:
-                    o = in_o | out_o | st_o | late
-                return d & ~o, o
+                def checked(_):
+                    d, in_o, out_o, st_o, late, _tr = pf.job_termination_flags(
+                        st, pen, in_cap, out_cap, store_log)
+                    # same policy split as the solo megaloop: under the
+                    # graceful-degradation overflow policy the channel
+                    # watermarks are counted loss, not aborts
+                    if cfg.faults is not None and cfg.faults.drop_overflow:
+                        o = st_o | late
+                    else:
+                        o = in_o | out_o | st_o | late
+                    return d & ~o, o
 
-            d, o = jax.lax.cond(
-                at_check, checked,
-                lambda _: (jnp.zeros_like(done), jnp.zeros_like(over)), None)
-            done = done | (active & d)
-            over = over | (active & o)
-            return st, pen, i, rounds, done, over
+                d, o = jax.lax.cond(
+                    at_check, checked,
+                    lambda _: (jnp.zeros_like(done), jnp.zeros_like(over)), None)
+                done = done | (active & d)
+                over = over | (active & o)
+                return st, pen, i, rounds, done, over
 
         st, pen, _i, rounds, done, over = jax.lax.while_loop(
             cond, body, (states, pending, jnp.int32(0), rounds, done, over))
