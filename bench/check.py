@@ -1,23 +1,17 @@
-"""The comparison that decides ``correct``.
+"""The comparison that decides ``correct``: the helpers every
+configuration kind's ``compare`` (``bench/kinds/<kind>.py``) is built from.
 
-Every number here counts disagreements, so each limit is 0: the platform's
-integer arithmetic and its simulated time are exact by design, and a
-single differing word, spike count, membrane, round or cycle is a wrong
-simulation.  The numbers are printed beside their limits.
+Every number a kind compares counts disagreements, so each limit in its
+``LIMITS`` is 0: the platform's integer arithmetic and its simulated time
+are exact by design, and a single differing word, spike count, membrane,
+round or cycle is a wrong simulation.  The numbers are printed beside
+their limits.
 """
 from __future__ import annotations
 
 import numpy as np
 
 from bench import reference
-
-# number -> limit; the order is the order they are printed in
-DENSE_LIMITS = {"failed_jobs": 0, "output_words_wrong": 0,
-                "jobs_rounds_off": 0, "jobs_cycles_off": 0}
-SNN_LIMITS = {"failed_jobs": 0, "spike_totals_wrong": 0,
-              "membranes_wrong": 0, "jobs_rounds_off": 0,
-              "jobs_cycles_off": 0}
-SERVE_LIMITS = {"requests_lost": 0, **SNN_LIMITS}
 
 
 def dense_wrong(job, o_words, precision: str = "exact",

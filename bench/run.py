@@ -5,8 +5,18 @@
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; its
 configuration and traffic mix are the files ``bench/configs/<config>.json``
 and ``bench/traffic/<traffic>.json``, and each per-layer metric is read by
-``bench/metrics/<metric>.py``.  Nothing here names a cell, so adding one is
-adding files and entries.
+``bench/metrics/<metric>.py``.  A configuration's ``kind`` names the module
+``bench/kinds/<kind>.py`` that makes its jobs from the seed, builds and
+places them, fetches and compares their results with the plain references,
+and computes its lower-precision control.  Nothing here names a cell, a
+configuration or a kind.
+
+To add a configuration, add files and entries and edit none: the
+configuration's file, a traffic file for each of its cells, their entries
+in ``BENCHMARK.json``, and, where no kind in ``bench/kinds/`` builds it, one
+new kind module with the interface ``job``, ``build``, ``fetch``,
+``compare``, ``LIMITS`` and ``control`` (and ``server`` and ``request``
+where it is served); ``bench/kinds/snn_network.py`` is an example.
 
 Set-up (``setup_s``, from process start to the window) builds a pool of
 jobs from the seed, places them and warms their shapes from the persistent
@@ -29,7 +39,6 @@ import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
-import statistics  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -130,10 +139,6 @@ def _trace_slice(seconds: float) -> None:
     jax.profiler.stop_trace()
 
 
-def p95(values) -> float:
-    return statistics.quantiles(values, n=20, method="inclusive")[-1]
-
-
 def run_cell(cell: dict, config: dict, traffic: dict, metrics: dict, *,
              seed: int, seconds: float, trace: bool, devices, peak: dict,
              t_start: float, clock: CompileClock | None = None) -> dict:
@@ -141,13 +146,14 @@ def run_cell(cell: dict, config: dict, traffic: dict, metrics: dict, *,
     result object.  ``devices`` are the chips the cell uses."""
     import jax
 
-    from bench import cells, trace_reduce
+    from bench import cells, kinds, trace_reduce
 
+    kind = kinds.find(config["kind"])
     serve = traffic["kind"] == "serve"
     if serve:
-        driver = cells.ServeCell(config, traffic, seed, devices)
+        driver = cells.ServeCell(config, traffic, seed, devices, kind=kind)
     else:
-        driver = cells.SimCell(config, traffic, seed)
+        driver = cells.SimCell(config, traffic, seed, kind=kind)
     driver.warm()
     setup_s = time.perf_counter() - t_start
     compiled_setup = (clock.total, clock.compiles) if clock else (0.0, 0)
@@ -198,19 +204,21 @@ def run_cell(cell: dict, config: dict, traffic: dict, metrics: dict, *,
         out["breakdown"] = {"device_ops": tr.top_ops(10),
                             "idle_gaps": tr.idle_gaps_by_span(10)}
     else:
-        e2e = {"setup_s": setup_s}
-        if serve:
-            e2e["requests_per_s"] = len(done) / window_s
-            e2e["request_p95_ms"] = 1000 * p95([d.latency_s for d in done])
-        else:
-            e2e["sim_cycles_per_s"] = driver.sim_cycles(done) / window_s
+        # a metric <quantity>.<suffix> reports the quantity under a bound
+        # of its own, for cells that spread more than the others
+        e2e = {"setup_s": setup_s,
+               "sim_cycles_per_s": cells.sim_cycles(done) / window_s}
         for m in metrics["end_to_end"]:
-            out["metrics"][m["name"]] = {"value": e2e[m["name"]],
-                                         "unit": m["unit"]}
+            out["metrics"][m["name"]] = {
+                "value": e2e[m["name"].split(".")[0]], "unit": m["unit"]}
 
     (correct, checks), failed = driver.verify(done)
-    log(f"window: {window_s:.3f} s, {len(done)} "
-        f"{'requests' if serve else 'jobs'}, {driver.rounds(done)} rounds")
+    what = "jobs"
+    if serve:
+        what = (f"requests in {driver.flushes} flushes of "
+                f"{', '.join(f'{t:.3f}' for t in driver.flush_s)} s")
+    log(f"window: {window_s:.3f} s, {len(done)} {what}, "
+        f"{driver.rounds(done)} rounds")
     errors = [d.error for d in done if d.error]
     if errors:
         log(f"{len(errors)} failed, the first with: {errors[0]}")

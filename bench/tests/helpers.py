@@ -2,7 +2,6 @@
 from __future__ import annotations
 
 import copy
-import json
 import time
 
 # per cell: overrides of its configuration and traffic that keep every
@@ -27,37 +26,11 @@ TINY = {
 }
 
 
-# the serving cell and its metrics, kept out of BENCHMARK.json until it has
-# run on four chips (PERF.md, Open questions)
-SERVE = {
-    "workloads": [{"name": "snn-fc1200-serve-4chip",
-                   "config": "snn-fc784-1200-1200-10",
-                   "traffic": "snn-serve-closed32", "chips": 4}],
-    "end_to_end": [
-        {"name": n, "unit": u, "workloads": ["snn-fc1200-serve-4chip"]}
-        for n, u in (("requests_per_s", "req/s"), ("request_p95_ms", "ms"))],
-    "per_layer": [
-        {"name": n, "unit": u, "workloads": ["snn-fc1200-serve-4chip"]}
-        for n, u in (("device_idle_pct.serve", "%"),
-                     ("device_ms_per_round.serve", "ms"),
-                     ("lif_step_roofline.serve", "%"))],
-}
-
-
-def spec_with_serve() -> dict:
-    """BENCHMARK.json with the serving cell added."""
-    from bench import run
-
-    spec = json.loads((run.ROOT / "BENCHMARK.json").read_text())
-    return {k: spec[k] + SERVE.get(k, []) if isinstance(spec[k], list)
-            else spec[k] for k in spec}
-
-
 def tiny_cell(name: str, chips: int | None = None):
     """(cell, config, traffic, metrics) of ``name`` cut to its tiny size."""
     from bench import run
 
-    cell, config, traffic, metrics = run.load_cell(name, spec_with_serve())
+    cell, config, traffic, metrics = run.load_cell(name)
     over_c, over_t = TINY[name]
     config, traffic = copy.deepcopy(config), copy.deepcopy(traffic)
     config.update(over_c)

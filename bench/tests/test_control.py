@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import pytest
 
-from bench import control
+from bench import kinds
 from bench.tests.helpers import TINY_SNN, tiny_cell
 
 
@@ -11,7 +11,7 @@ from bench.tests.helpers import TINY_SNN, tiny_cell
 def test_dense_control_is_refused(seed):
     _, config, traffic, _ = tiny_cell("dense-cim-imagenet-conv1")
     traffic = {**traffic, "layer": {"h": 32, "w": 64, "p": 4}}
-    vals = control.numbers(config, traffic, seed)
+    vals = kinds.find(config["kind"]).control(config, traffic, seed)
     assert vals["output_words_wrong"] > 0
 
 
@@ -21,15 +21,15 @@ def test_dense_control_is_refused(seed):
 def test_snn_control_is_refused(cell, seed):
     _, config, traffic, _ = tiny_cell(cell)
     assert config["layer_sizes"] == TINY_SNN["layer_sizes"]
-    vals = control.numbers(config, traffic, seed)
+    vals = kinds.find(config["kind"]).control(config, traffic, seed)
     assert vals["membranes_wrong"] > 0
 
 
 def test_the_exact_reference_in_the_controls_place_passes():
-    from bench import check, jobs, reference
+    from bench import check, reference
 
     _, config, traffic, _ = tiny_cell("snn-fc1200-infer")
-    job = jobs.snn_job(config, 6, 0.2, 7, 0)
+    job = kinds.find(config["kind"]).job(config, traffic, 7, 0)
     ticks = reference.feedforward_horizon(6, len(job.weights))
     ref = check.snn_reference(job, ticks)
     groups = [(l, 0, x.shape[1], 0, l) for l, x in enumerate(ref[0])]

@@ -7,8 +7,11 @@ import json
 import numpy as np
 import pytest
 
-from bench import jobs, reference
+from bench import jobs, kinds, reference
 from bench.run import BENCH
+
+DENSE = kinds.find("dense_platform")
+SNN = kinds.find("snn_network")
 
 
 def _config(name):
@@ -21,13 +24,13 @@ def test_programs_assemble_like_the_programs_own(h, w, p):
     from repro.vp import workloads as wl
     from repro.vp.assembler import assemble, vmm_riscv_program
 
-    ours = jobs.riscv_program(h, w, p, 4096, 8192, 12288)
+    ours = DENSE.riscv_program(h, w, p, 4096, 8192, 12288)
     assert np.array_equal(assemble(ours),
                           assemble(vmm_riscv_program(h, w, p, 4096, 8192,
                                                      12288)))
     bases = (sg.cim_global_base(2), sg.cim_global_base(3))
     for ords in ((0, 1), (1, 0)):
-        ours = jobs.cim_pair_program(bases, h, w, 1, p, 12288, p, ords)
+        ours = DENSE.cim_pair_program(bases, h, w, 1, p, 12288, p, ords)
         theirs = wl.cim_pair_program(bases, h, w, 1, p, 12288, p,
                                      ordinals=ords)
         assert np.array_equal(assemble(ours), assemble(theirs))
@@ -40,8 +43,8 @@ def test_dense_job_wires_units_like_the_program():
     config = _config("paper-2x2-uniform")
     traffic = {"layer": {"h": 8, "w": 8, "p": 5}, "mode": "cim",
                "managers": [0, 1]}
-    job = jobs.dense_job(config, traffic, seed=3, index=0)
-    descs = jobs.platform_descs(config)
+    job = DENSE.job(config, traffic, seed=3, index=0)
+    descs = DENSE.platform_descs(config)
     theirs = wl.cim_workload(wl.Layer("t", "t", 8, 8, 5), [0, 1],
                              {0: (0, 1), 1: (2, 3)},
                              ordinals=sg.mailbox_ordinals(descs))
@@ -57,7 +60,7 @@ def test_lif_oracle_matches_the_programs_oracle():
 
     config = {**_config("snn-fc784-1200-1200-10"),
               "layer_sizes": [40, 30, 20, 10]}
-    job = jobs.snn_job(config, t_steps=7, rate=0.3, seed=2**33 + 1, index=2)
+    job = SNN.job(config, {"rate": 0.3}, seed=2**33 + 1, index=2, t_steps=7)
     layers = [snn.SNNLayer(w, snn.LIFParams(thresh=t, leak=job.leak))
               for w, t in zip(job.weights, job.thresh)]
     counts, totals = snn.oracle_run(layers, job.raster)
@@ -76,18 +79,32 @@ def test_serve_lengths_are_the_same_multiset_for_every_seed():
     assert sorted(a) == sorted(b) == sorted([8, 12, 16] * 8)
 
 
+def test_every_flush_sends_the_same_mix_of_lengths_on_every_seed():
+    traffic = {"t_steps_choices": [8, 12, 16], "pool": 12}
+    mixes, sent = set(), set()
+    for seed in (1, 2**40 + 3):
+        ts = jobs.serve_t_steps(traffic, seed)
+        for flush in range(4):
+            entries = [jobs.serve_entry(ts, c, flush) for c in range(32)]
+            mixes.add(tuple(sorted(ts[e] for e in entries)))
+            sent.update((seed, e) for e in entries)
+    assert mixes == {(8,) * 11 + (12,) * 11 + (16,) * 10}
+    assert len(sent) == 2 * 12
+
+
 def test_jobs_follow_the_seed():
     config = _config("snn-fc784-1200-1200-10")
-    a = jobs.snn_job(config, 4, 0.2, 2**32 + 5, 0)
-    b = jobs.snn_job(config, 4, 0.2, 2**32 + 5, 0)
-    c = jobs.snn_job(config, 4, 0.2, 2**32 + 6, 0)
+    traffic = {"t_steps": 4, "rate": 0.2}
+    a = SNN.job(config, traffic, 2**32 + 5, 0)
+    b = SNN.job(config, traffic, 2**32 + 5, 0)
+    c = SNN.job(config, traffic, 2**32 + 6, 0)
     assert np.array_equal(a.raster, b.raster)
     assert all(np.array_equal(x, y) for x, y in zip(a.weights, b.weights))
     assert not np.array_equal(a.weights[0], c.weights[0])
 
 
 def test_crossbar_units_of_the_published_network():
-    assert jobs.crossbar_units([784, 1200, 1200, 10]) == 50
+    assert SNN.crossbar_units([784, 1200, 1200, 10]) == 50
 
 
 @pytest.mark.parametrize("check_every,rate", [(2, 0.3), (4, 0.3), (2, 0.05)])
@@ -95,8 +112,9 @@ def test_snn_rounds_match_the_program(check_every, rate):
     from bench.tests.helpers import TINY_SNN
 
     config = {**_config("snn-fc784-1200-1200-10"), **TINY_SNN}
-    job = jobs.snn_job(config, 6, rate, seed=2**35 + check_every, index=1)
-    cfg, states, pending, _ = jobs.build_snn(config, job, use_kernel=False)
+    job = SNN.job(config, {"t_steps": 6, "rate": rate},
+                  seed=2**35 + check_every, index=1)
+    cfg, states, pending, _ = SNN.build(config, {"use_kernel": False}, job)
     from repro.core.controller import Controller
 
     ctl = Controller(cfg, states, pending, backend="vmap",

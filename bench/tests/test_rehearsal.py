@@ -25,7 +25,8 @@ def test_one_chip_cells_run_and_check(cell):
 
 
 def test_serve_cell_runs_on_four_devices():
-    """In a subprocess: the virtual devices must exist before jax starts."""
+    """The cell as ``BENCHMARK.json`` enters it, cut to its tiny size.  In a
+    subprocess: the virtual devices must exist before jax starts."""
     code = ("import json, sys; sys.path[:0] = ['.', 'src'];"
             "from bench.tests.helpers import rehearse;"
             "print(json.dumps(rehearse('snn-fc1200-serve-4chip', "
@@ -39,8 +40,8 @@ def test_serve_cell_runs_on_four_devices():
     assert res["correct"], res["checks"]
     assert res["device"]["count"] == 4
     assert res["attempted"] >= 8 and res["failed"] == 0
-    assert set(res["metrics"]) == {"requests_per_s", "request_p95_ms",
-                                   "setup_s"}
+    assert set(res["metrics"]) == {"sim_cycles_per_s.serve", "setup_s"}
+    assert res["metrics"]["sim_cycles_per_s.serve"]["value"] > 0
 
 
 @pytest.mark.parametrize("cell", ["snn-fc1200-infer",

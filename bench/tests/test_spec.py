@@ -7,16 +7,23 @@ import re
 
 import pytest
 
-from bench import run
+from bench import kinds, run
 
 SPEC = json.loads((run.ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
+INTERFACE = ("job", "build", "fetch", "compare", "LIMITS", "control")
+SERVING = ("server", "request")
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
 def test_every_cell_resolves(cell):
     c, config, traffic, metrics = run.load_cell(cell)
-    assert config["kind"] in ("dense_platform", "snn_network")
+    kind = kinds.find(config["kind"])
+    for name in INTERFACE:
+        assert hasattr(kind, name), (config["kind"], name)
+    assert all(limit == 0 for limit in kind.LIMITS.values())
     assert traffic["kind"] in ("dense", "snn", "serve")
     names = [m["name"] for m in metrics["end_to_end"]]
     assert "setup_s" in names and len(names) >= 2
@@ -39,15 +46,31 @@ def test_names_units_and_files():
     assert all(m["bound"] <= 0.25 for m in SPEC["end_to_end"])
 
 
-def test_the_serving_cell_resolves_beside_the_spec():
-    from bench.tests.helpers import spec_with_serve
-
-    c, config, traffic, metrics = run.load_cell("snn-fc1200-serve-4chip",
-                                                spec_with_serve())
-    assert traffic["kind"] == "serve" and c["chips"] == 4
+@pytest.mark.parametrize("cell", [
+    w["name"] for w in SPEC["workloads"]
+    if run.load_cell(w["name"])[2]["kind"] == "serve"])
+def test_serve_cells_are_entered_on_a_serving_kind(cell):
+    c, config, traffic, metrics = run.load_cell(cell)
+    kind = kinds.find(config["kind"])
+    for name in SERVING:
+        assert hasattr(kind, name), (config["kind"], name)
     assert traffic["bucket"] % c["chips"] == 0
-    for m in metrics["per_layer"]:
-        assert callable(run.reader(m["name"]))
+    rates = [m for m in metrics["end_to_end"] if m["unit"] == "cycles/s"]
+    assert len(rates) == 1
+
+
+def test_an_unknown_kind_is_refused():
+    with pytest.raises(SystemExit, match="bench/kinds/"):
+        kinds.find("no_such_kind")
+
+
+def test_serve_traffic_on_a_kind_without_a_serving_builder_is_refused():
+    from bench import cells
+
+    _, config, traffic, _ = run.load_cell("snn-fc1200-serve-4chip")
+    dense = kinds.find("dense_platform")
+    with pytest.raises(SystemExit, match="dense_platform.py"):
+        cells.ServeCell(config, traffic, 1, [None], kind=dense)
 
 
 def test_an_unknown_cell_is_refused():
